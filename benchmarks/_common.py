@@ -14,7 +14,12 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 SCHEMA_VERSION = 1
+
+# every bench imports this module before its first compile
+enable_compile_cache()
 
 
 def repo_root() -> str:

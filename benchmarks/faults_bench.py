@@ -158,7 +158,10 @@ def engine_recovery(smoke: bool) -> Dict:
         argv.append("--smoke")
     proc = subprocess.run(
         argv, capture_output=True, text=True, timeout=900,
-        env={**os.environ, "PYTHONPATH": os.path.join(root, "src")})
+        # the 8-device driver is a CPU simulation by design; keep it off
+        # any chip this process holds
+        env={**os.environ, "PYTHONPATH": os.path.join(root, "src"),
+             "JAX_PLATFORMS": "cpu"})
     if proc.returncode != 0:
         raise RuntimeError("faults driver failed:\n"
                            + proc.stdout + proc.stderr)

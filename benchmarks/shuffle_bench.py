@@ -89,14 +89,16 @@ def run(verbose: bool = True, iters: int = 10, smoke: bool = False) -> list:
     subprocess — the reduced CI profile."""
     rows = _kernel_times(iters=iters, smoke=smoke) + _r_sweep()
     if not smoke:
-        # distributed shuffle in a subprocess (needs 8 host devices)
+        # distributed shuffle in a subprocess: a CPU simulation on 8 host
+        # devices by design, kept off any chip this process holds
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         t0 = time.perf_counter()
         proc = subprocess.run(
             [sys.executable,
              os.path.join(root, "tests", "multidevice", "driver_shuffle.py")],
             capture_output=True, text=True, timeout=900,
-            env={**os.environ, "PYTHONPATH": os.path.join(root, "src")})
+            env={**os.environ, "PYTHONPATH": os.path.join(root, "src"),
+                 "JAX_PLATFORMS": "cpu"})
         ok = proc.returncode == 0 and "ALL MULTIDEVICE" in proc.stdout
         rows.append(("distributed_hybrid_shuffle_8dev_r123",
                      (time.perf_counter() - t0) * 1e6,

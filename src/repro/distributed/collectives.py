@@ -27,8 +27,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .meshes import axis_size
-
 
 def hierarchical_psum(x: jax.Array, fast_axis: str, slow_axis: str,
                       scatter_dim: int = 0) -> jax.Array:
@@ -63,8 +61,8 @@ def hierarchical_all_to_all(x: jax.Array, fast_axis: str, slow_axis: str,
     carries each byte exactly once in 1 bundled flow instead of Kr
     distinct flows — the schedule the roofline's cross-pod term wants.
     """
-    n_slow = axis_size(slow_axis)
-    n_fast = axis_size(fast_axis)
+    n_slow = jax.lax.axis_size(slow_axis)
+    n_fast = jax.lax.axis_size(fast_axis)
     n = x.shape[split_axis]
     assert n == n_slow * n_fast, (n, n_slow, n_fast)
 

@@ -1,63 +1,16 @@
-"""Version-tolerant mesh / shard_map construction.
+"""Mesh construction for the repo's named-axis programs.
 
-JAX moved two APIs this repo leans on:
-
-  * ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-    ``jax.make_mesh``) only exist on jax >= 0.5; on 0.4.x meshes carry no
-    axis types and ``jax.make_mesh`` rejects the kwarg.
-  * ``jax.shard_map`` was promoted out of ``jax.experimental.shard_map``
-    and its replication-check kwarg was renamed ``check_rep`` ->
-    ``check_vma`` along the way.
-
-Every call site in the repo routes through :func:`make_mesh` /
-:func:`shard_map` below so the rest of the codebase is version-agnostic.
+The shard_map programs here (shuffle, fused engine, trainer sync) place
+their own inputs and name their own partition specs, so their meshes use
+Auto axis types rather than ``jax.make_mesh``'s Explicit default, under
+which jit would also type every intermediate's sharding.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: tuple, names: tuple) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported (>= 0.5),
-    plain mesh construction otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, names)
-    return jax.make_mesh(shape, names,
-                         axis_types=(axis_type.Auto,) * len(names))
-
-
-def axis_size(axis_name: str):
-    """Size of a named mesh axis inside shard_map (``jax.lax.axis_size`` on
-    new jax; the constant-folding ``psum(1, axis)`` idiom on 0.4.x)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:                                     # jax 0.4.x
-        from jax.experimental.shard_map import shard_map as fn
-    params = inspect.signature(fn).parameters
-    if "check_vma" in params:
-        check_kw = "check_vma"
-    elif "check_rep" in params:
-        check_kw = "check_rep"
-    else:
-        check_kw = None
-    return fn, check_kw
-
-
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check: bool = True):
-    """Version-tolerant ``shard_map``.
-
-    ``check=False`` disables the replication validity check, whatever the
-    installed jax calls it (``check_vma`` on new jax, ``check_rep`` on 0.4.x).
-    """
-    fn, check_kw = _resolve_shard_map()
-    kwargs = {} if (check or check_kw is None) else {check_kw: False}
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    """``jax.make_mesh`` over the default devices with Auto axis types."""
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names))

@@ -7,9 +7,13 @@ On TPU this encode/decode is a *memory-bound* fused multiply-accumulate
 over the payload tensors — the hot inner loop of the shuffle engine, fused
 here so each tile is read from HBM exactly once into VMEM.
 
-Tiling: payloads are flattened to [T, d] tiles; the stream axis r is small
-(the map replication factor, 2-4) and unrolled inside the kernel.  Block
-shape (block_t, d) with d padded to the 128-lane boundary by ops.py.
+Tiling: payloads are flattened to [T, d]; the stream axis r is small
+(the map replication factor, 2-4) and unrolled inside the kernel.  The grid
+is (T / block_t, d / block_d) with d padded to the 128-lane boundary by
+ops.py.  block_d is capped (:func:`lane_block`) so the VMEM working set —
+r input tiles plus the output tile, each double-buffered — stays inside the
+default scoped VMEM limit at any shuffle width: a full-d block at
+(r=3, d=2048) or (r=2, d=4096) does not fit.
 
 An XOR (GF(2)) variant is provided for bit-exact integer shuffles
 (CodedTeraSort-style): f = v_1 ^ ... ^ v_r, decode by re-XOR.
@@ -55,18 +59,30 @@ def _xor_decode_kernel(f_ref, x_ref, o_ref, *, r: int):
     o_ref[...] = acc
 
 
+_MAX_BLOCK_D = 512
+
+
+def lane_block(d: int) -> int:
+    """Largest multiple of 128 that divides ``d`` and is <= _MAX_BLOCK_D.
+    ``d`` is 128-aligned (ops.py pads it).  At block_t=256, r=4, f32 that
+    is 4 MiB of double-buffered input tiles."""
+    assert d % 128 == 0, d
+    return max(b for b in range(128, min(d, _MAX_BLOCK_D) + 1, 128)
+               if d % b == 0)
+
+
 def encode_pallas(streams: jax.Array, coeffs: jax.Array, *,
                   block_t: int = 256, interpret: bool = True) -> jax.Array:
     """streams: [r, T, d]; coeffs: [r] fp32 -> f [T, d] (streams dtype)."""
     r, T, d = streams.shape
-    nt = T // block_t
+    bd = lane_block(d)
     return pl.pallas_call(
         functools.partial(_encode_kernel, r=r),
         out_shape=jax.ShapeDtypeStruct((T, d), streams.dtype),
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((r, block_t, d), lambda i: (0, i, 0)),
-                  pl.BlockSpec((r,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((block_t, d), lambda i: (i, 0)),
+        grid=(T // block_t, d // bd),
+        in_specs=[pl.BlockSpec((r, block_t, bd), lambda i, k: (0, i, k)),
+                  pl.BlockSpec((r,), lambda i, k: (0,))],
+        out_specs=pl.BlockSpec((block_t, bd), lambda i, k: (i, k)),
         interpret=interpret,
     )(streams, coeffs.astype(jnp.float32))
 
@@ -75,15 +91,15 @@ def decode_pallas(f: jax.Array, known: jax.Array, coeffs: jax.Array, *,
                   block_t: int = 256, interpret: bool = True) -> jax.Array:
     """f: [T, d]; known: [r-1, T, d]; coeffs: [r] (missing first)."""
     rm1, T, d = known.shape
-    nt = T // block_t
+    bd = lane_block(d)
     return pl.pallas_call(
         functools.partial(_decode_kernel, r=rm1 + 1),
         out_shape=jax.ShapeDtypeStruct((T, d), f.dtype),
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((block_t, d), lambda i: (i, 0)),
-                  pl.BlockSpec((rm1, block_t, d), lambda i: (0, i, 0)),
-                  pl.BlockSpec((rm1 + 1,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((block_t, d), lambda i: (i, 0)),
+        grid=(T // block_t, d // bd),
+        in_specs=[pl.BlockSpec((block_t, bd), lambda i, k: (i, k)),
+                  pl.BlockSpec((rm1, block_t, bd), lambda i, k: (0, i, k)),
+                  pl.BlockSpec((rm1 + 1,), lambda i, k: (0,))],
+        out_specs=pl.BlockSpec((block_t, bd), lambda i, k: (i, k)),
         interpret=interpret,
     )(f, known, coeffs.astype(jnp.float32))
 
@@ -91,13 +107,13 @@ def decode_pallas(f: jax.Array, known: jax.Array, coeffs: jax.Array, *,
 def xor_encode_pallas(streams: jax.Array, *, block_t: int = 256,
                       interpret: bool = True) -> jax.Array:
     r, T, d = streams.shape
-    nt = T // block_t
+    bd = lane_block(d)
     return pl.pallas_call(
         functools.partial(_xor_encode_kernel, r=r),
         out_shape=jax.ShapeDtypeStruct((T, d), streams.dtype),
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((r, block_t, d), lambda i: (0, i, 0))],
-        out_specs=pl.BlockSpec((block_t, d), lambda i: (i, 0)),
+        grid=(T // block_t, d // bd),
+        in_specs=[pl.BlockSpec((r, block_t, bd), lambda i, k: (0, i, k))],
+        out_specs=pl.BlockSpec((block_t, bd), lambda i, k: (i, k)),
         interpret=interpret,
     )(streams)
 
@@ -105,13 +121,13 @@ def xor_encode_pallas(streams: jax.Array, *, block_t: int = 256,
 def xor_decode_pallas(f: jax.Array, known: jax.Array, *, block_t: int = 256,
                       interpret: bool = True) -> jax.Array:
     rm1, T, d = known.shape
-    nt = T // block_t
+    bd = lane_block(d)
     return pl.pallas_call(
         functools.partial(_xor_decode_kernel, r=rm1 + 1),
         out_shape=jax.ShapeDtypeStruct((T, d), f.dtype),
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((block_t, d), lambda i: (i, 0)),
-                  pl.BlockSpec((rm1, block_t, d), lambda i: (0, i, 0))],
-        out_specs=pl.BlockSpec((block_t, d), lambda i: (i, 0)),
+        grid=(T // block_t, d // bd),
+        in_specs=[pl.BlockSpec((block_t, bd), lambda i, k: (i, k)),
+                  pl.BlockSpec((rm1, block_t, bd), lambda i, k: (0, i, k))],
+        out_specs=pl.BlockSpec((block_t, bd), lambda i, k: (i, k)),
         interpret=interpret,
     )(f, known)

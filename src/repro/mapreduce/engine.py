@@ -14,7 +14,7 @@ Two execution paths:
     jitted, device-resident shard_map program: each device maps only its own
     n_loc assigned subfiles, packs via on-device gathers from the plan's
     cached index-table constants, shuffles, and reduces its own keys — zero
-    host transfers between phases, input buffer donated.  ``fused=False``
+    host transfers between phases.  ``fused=False``
     keeps the legacy host-round-trip path (single-device map of all N, host
     NumPy packing, re-upload) for comparison — see
     ``benchmarks/pipeline_bench.py``.
@@ -30,7 +30,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.assignment import (coded_assignment, hybrid_assignment,
                                uncoded_assignment)
@@ -47,7 +47,6 @@ from ..core.params import SchemeParams
 from ..core.plan_registry import scheme_of_family
 from ..core.resolvable import resolvable_assignment
 from ..core.shuffle_plan import count_plan, make_plan
-from ..distributed.meshes import shard_map
 from ..obs.bytes import plan_rack_bytes, reconcile, record_rack_bytes
 from ..obs.metrics import refresh_cache_metrics
 from ..obs.tracing import get_tracer, spans_from_phase_timings
@@ -147,6 +146,13 @@ def pack_local_subfiles(subfiles: np.ndarray,
     return np.asarray(subfiles)[plan.local_subfiles.reshape(p.K, -1)]
 
 
+def put_per_device(x: np.ndarray, mesh: Mesh) -> jax.Array:
+    """Upload a host [K, ...] per-device array straight to its devices: row
+    (i*Kr + j) goes to mesh device (i, j) only, so no single device ever
+    holds the whole (r-fold replicated) input."""
+    return jax.device_put(x, NamedSharding(mesh, P(("rack", "server"))))
+
+
 def assemble_outputs(out: jax.Array, plan: HybridShufflePlan) -> jax.Array:
     """[K, Q/K, d_out] per-server reduce rows -> [Q, d_out] in global key
     order, derived explicitly from :func:`reduce_output_keys` (row m of the
@@ -154,7 +160,13 @@ def assemble_outputs(out: jax.Array, plan: HybridShufflePlan) -> jax.Array:
     default contiguous partition)."""
     keys = reduce_output_keys(plan)
     flat = out.reshape(out.shape[0] * out.shape[1], -1)
-    return flat[np.argsort(keys.reshape(-1), kind="stable")]
+    order = np.argsort(keys.reshape(-1), kind="stable")
+    if isinstance(out.sharding, NamedSharding):
+        # a gather across the mesh-sharded row axis: name where the result
+        # lives (replicated), as an Explicit-axis mesh requires
+        return flat.at[order].get(
+            out_sharding=NamedSharding(out.sharding.mesh, P()))
+    return flat[order]
 
 
 @functools.lru_cache(maxsize=64)
@@ -166,10 +178,11 @@ def _fused_executable(job: MapReduceJob, plan: HybridShufflePlan, mesh: Mesh,
 
     The cache keys on the job OBJECT (its map/reduce closures compare by
     identity, standard jit semantics) — reuse one job instance across calls
-    to hit the compiled executable; a fresh factory call recompiles.  The
-    packed input is donated so XLA may reuse its buffer where shapes/dtypes
-    admit aliasing; intermediates of the fused program are XLA-managed and
-    never materialize host-side at all."""
+    to hit the compiled executable; a fresh factory call recompiles.
+    Intermediates of the fused program are XLA-managed and never
+    materialize host-side at all.  The packed input is not donated: no
+    output has its shape, so XLA could alias nothing (it warns on the chip
+    and copies on the CPU)."""
     p = plan.params
     tables = device_plan_tables(plan)       # on-device constants, plan-cached
 
@@ -179,15 +192,12 @@ def _fused_executable(job: MapReduceJob, plan: HybridShufflePlan, mesh: Mesh,
                                    combine_impl)                # [N,q_srv,d]
         return jax.vmap(job.reduce_fn, in_axes=1)(rows)[None]   # [1,q_srv,*]
 
-    fn = shard_map(device_fn, mesh=mesh,
-                   in_specs=(P(("rack", "server")),),
-                   out_specs=P(("rack", "server")),
-                   check=combine_impl != "pallas")
-    # donate the packed input: XLA aliases it into the program where
-    # shapes/dtypes admit (a no-op otherwise); donation is unimplemented on
-    # the cpu backend (warns and copies), so gate it
-    donate = (0,) if jax.default_backend() != "cpu" else ()
-    return jax.jit(fn, donate_argnums=donate)
+    # check_vma off for pallas: see coded_collectives.hybrid_shuffle
+    fn = jax.shard_map(device_fn, mesh=mesh,
+                       in_specs=(P(("rack", "server")),),
+                       out_specs=P(("rack", "server")),
+                       check_vma=combine_impl != "pallas")
+    return jax.jit(fn)
 
 
 def _blame_from_spans(events, cost) -> Dict[str, float] | None:
@@ -283,7 +293,8 @@ def run_job_distributed(job: MapReduceJob, subfiles: np.ndarray,
         plan = compile_hybrid_plan(p, perm=perm, family=scheme_family)
     if fused:
         with tracer.span("pack", kind="engine_phase", job=job.name):
-            local_subs = jnp.asarray(pack_local_subfiles(subfiles, plan))
+            local_subs = put_per_device(
+                pack_local_subfiles(subfiles, plan), mesh)
         with tracer.span("map_shuffle_reduce", kind="engine_phase",
                          job=job.name, fused="true"):
             exe = _fused_executable(job, plan, mesh, multicast, combine_impl)

@@ -38,22 +38,24 @@ def groupby_mean_job() -> MapReduceJob:
     return MapReduceJob("groupby_mean", 2, map_fn, reduce_fn)
 
 
-def wide_histogram_job(d: int) -> MapReduceJob:
+def wide_histogram_job(d: int, dtype=jnp.float32) -> MapReduceJob:
     """Histogram with a width-d payload per (key, subfile): counts scaled by
-    a fixed integer weight vector.  Integer-valued float32 throughout, so
-    every execution path (including coded multicast encode/decode) is
-    bit-exact — the shuffle-bound workload of ``benchmarks/pipeline_bench``.
+    a fixed integer weight vector.  Integer-valued throughout, so every
+    execution path (including coded multicast encode/decode) is bit-exact —
+    the shuffle-bound workload of ``benchmarks/pipeline_bench``.  An integer
+    ``dtype`` admits the GF(2) ``multicast='coded_xor'`` wire format.
     """
     def map_fn(tokens: jax.Array, Q: int) -> jax.Array:
         bucket = (tokens.astype(jnp.uint32) % jnp.uint32(Q)).astype(jnp.int32)
-        counts = jnp.zeros((Q,), jnp.float32).at[bucket].add(1.0)
-        w = (jnp.arange(d, dtype=jnp.float32) % 7.0) + 1.0
+        counts = jnp.zeros((Q,), dtype).at[bucket].add(1)
+        w = (jnp.arange(d, dtype=dtype) % 7) + 1
         return counts[:, None] * w[None, :]                  # [Q, d]
 
     def reduce_fn(vals: jax.Array) -> jax.Array:             # [N, d]
         return vals.sum(axis=0)
 
-    return MapReduceJob(f"wide_histogram_d{d}", d, map_fn, reduce_fn)
+    suffix = "" if dtype == jnp.float32 else f"_{jnp.dtype(dtype).name}"
+    return MapReduceJob(f"wide_histogram_d{d}{suffix}", d, map_fn, reduce_fn)
 
 
 def terasort_bucket_job(key_space: int = 2**20,

@@ -40,7 +40,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..core.degraded import DegradedPlan, build_patch, compile_degraded_plan
 from ..core.coded_collectives import device_plan_tables, shuffle_device_body
 from ..core.params import SchemeParams
-from ..distributed.meshes import shard_map
 from ..obs import metrics as obs_metrics
 from ..obs.bytes import degraded_rack_bytes, record_rack_bytes
 from ..resilience.backoff import RestartBudget
@@ -102,10 +101,11 @@ def _degraded_executable(job, dplan: DegradedPlan, mesh: Mesh,
                                    patch=patch[0].astype(vals.dtype))
         return jax.vmap(job.reduce_fn, in_axes=1)(rows)[None]   # [1,q_srv,*]
 
-    fn = shard_map(device_fn, mesh=mesh,
-                   in_specs=(P(("rack", "server")), P(("rack", "server"))),
-                   out_specs=P(("rack", "server")),
-                   check=combine_impl != "pallas")
+    # check_vma off for pallas: see coded_collectives.hybrid_shuffle
+    fn = jax.shard_map(device_fn, mesh=mesh,
+                       in_specs=(P(("rack", "server")), P(("rack", "server"))),
+                       out_specs=P(("rack", "server")),
+                       check_vma=combine_impl != "pallas")
     return jax.jit(fn)
 
 
@@ -114,7 +114,7 @@ def _degraded_attempt(job, subfiles: np.ndarray, p: SchemeParams, mesh: Mesh,
                       combine_impl: str, placement, scheme_family: str):
     """Rungs 1-2: degraded execution around ``failed``; returns
     (outputs [K, q_srv, d_out], degraded plan, n_remapped, rung)."""
-    from .engine import pack_local_subfiles
+    from .engine import pack_local_subfiles, put_per_device
     if len(failed) >= p.K:
         raise UnrecoverableFailure(
             f"all {p.K} servers failed; no survivors to recover on")
@@ -125,7 +125,8 @@ def _degraded_attempt(job, subfiles: np.ndarray, p: SchemeParams, mesh: Mesh,
         raise UnrecoverableFailure(
             f"{n_remap} subfiles lost all {p.r} owners and partial re-map "
             f"is disabled")
-    local_subs = jnp.asarray(pack_local_subfiles(subfiles, dplan.base))
+    local_subs = put_per_device(pack_local_subfiles(subfiles, dplan.base),
+                                mesh)
     q_rack = p.Q // p.P
     if n_remap:
         # rung 2: re-map ONLY the orphaned subfiles on survivors (the
@@ -138,7 +139,7 @@ def _degraded_attempt(job, subfiles: np.ndarray, p: SchemeParams, mesh: Mesh,
         patch = np.zeros((p.K, p.subfiles_per_layer, q_rack, job.d),
                          dtype=np.float32)
     exe = _degraded_executable(job, dplan, mesh, combine_impl)
-    out = exe(local_subs, jnp.asarray(patch))
+    out = exe(local_subs, put_per_device(patch, mesh))
     rung = "partial_remap" if n_remap else "decode_around"
     return out, dplan, n_remap, rung
 

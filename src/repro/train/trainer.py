@@ -35,7 +35,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..configs.base import ArchConfig
 from ..core.gradient_sync import coded_reduce_scatter_r2
 from ..distributed import sharding as shlib
-from ..distributed.meshes import shard_map
 from ..models import lm
 from .optimizer import (OptimizerConfig, adamw_update, init_opt_state,
                         optimizer_update)
@@ -195,9 +194,9 @@ def coded_grads_r2(params, cfg: ArchConfig, tc: TrainConfig,
         return full[None], loss[None]
 
     in_spec = (P(pod_axis),) + tuple(P() for _ in flat_params)
-    fn = shard_map(pod_fn, mesh=mesh, in_specs=in_spec,
-                   out_specs=(P(pod_axis), P(pod_axis)),
-                   check=False)
+    fn = jax.shard_map(pod_fn, mesh=mesh, in_specs=in_spec,
+                       out_specs=(P(pod_axis), P(pod_axis)),
+                       check_vma=False)
     full, loss = fn(jax.tree.map(lambda x: x, coded_batch), *flat_params)
     vec = full[0]                                 # identical across pods
     loss = loss.mean()

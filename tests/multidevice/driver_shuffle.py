@@ -23,7 +23,7 @@ from repro.core.coded_collectives import (                     # noqa: E402
 from repro.core.gradient_sync import (                         # noqa: E402
     chunk_index_table, coded_reduce_scatter_r2, hierarchical_allreduce,
     uncoded_reduce_scatter)
-from repro.distributed.meshes import make_mesh, shard_map      # noqa: E402
+from repro.distributed.meshes import make_mesh                 # noqa: E402
 from repro.mapreduce.engine import run_job, run_job_distributed  # noqa: E402
 from repro.mapreduce.jobs import histogram_job, groupby_mean_job  # noqa: E402
 
@@ -225,9 +225,9 @@ def test_coded_reduce_scatter():
     def body(x):
         return coded_reduce_scatter_r2(x[0], "rack", P_)[None]
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(("rack", "server")),),
-                   out_specs=P(("rack", "server")))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(("rack", "server")),),
+                       out_specs=P(("rack", "server")))
     out = np.asarray(fn(inp))                          # [8, G/P]
     for rack in range(P_):
         for srv in range(2):
@@ -241,9 +241,9 @@ def test_coded_reduce_scatter():
         return coded_reduce_scatter_r2(x[0], "rack", P_,
                                        combine_impl="pallas")[None]
 
-    fn_pl = shard_map(body_pl, mesh=mesh,
-                      in_specs=(P(("rack", "server")),),
-                      out_specs=P(("rack", "server")), check=False)
+    fn_pl = jax.shard_map(body_pl, mesh=mesh,
+                          in_specs=(P(("rack", "server")),),
+                          out_specs=P(("rack", "server")), check_vma=False)
     np.testing.assert_allclose(np.asarray(fn_pl(inp)), out, rtol=1e-6)
     print("coded reduce-scatter combine_impl=pallas: OK (== xla path)")
 
@@ -251,9 +251,9 @@ def test_coded_reduce_scatter():
     def body_f(x):
         return coded_reduce_scatter_r2(x[0], "rack", P_, failed=3)[None]
 
-    fn_f = shard_map(body_f, mesh=mesh,
-                     in_specs=(P(("rack", "server")),),
-                     out_specs=P(("rack", "server")))
+    fn_f = jax.shard_map(body_f, mesh=mesh,
+                         in_specs=(P(("rack", "server")),),
+                         out_specs=P(("rack", "server")))
     out_f = np.asarray(fn_f(inp))
     for rack in range(P_ - 1):                         # survivors only
         shard = total.reshape(P_, G // P_)[rack]
@@ -269,9 +269,9 @@ def test_hierarchical_allreduce():
     def body(v):
         return hierarchical_allreduce(v[0], "server", "rack")[None]
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(("rack", "server")),),
-                   out_specs=P(("rack", "server")))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(("rack", "server")),),
+                       out_specs=P(("rack", "server")))
     out = np.asarray(fn(jnp.asarray(x)))
     for d in range(8):
         np.testing.assert_allclose(out[d], x.sum(axis=0), rtol=1e-5)

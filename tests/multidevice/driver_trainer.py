@@ -16,7 +16,7 @@ from repro.configs import ARCHS                                # noqa: E402
 from repro.data.pipeline import SyntheticPipeline              # noqa: E402
 from repro.distributed.collectives import (                    # noqa: E402
     flat_all_to_all, hierarchical_all_to_all)
-from repro.distributed.meshes import make_mesh, shard_map      # noqa: E402
+from repro.distributed.meshes import make_mesh                 # noqa: E402
 from repro.train.optimizer import OptimizerConfig              # noqa: E402
 from repro.train.trainer import (TrainConfig,                  # noqa: E402
                                  init_train_state,
@@ -47,9 +47,9 @@ def test_hierarchical_a2a_equals_flat():
     x = jnp.arange(8 * 8 * 6, dtype=jnp.float32).reshape(8, 8, 6)
 
     def run(fn):
-        f = shard_map(lambda a: fn(a[0])[None], mesh=mesh,
-                      in_specs=(P(("pod", "data")),),
-                      out_specs=P(("pod", "data")))
+        f = jax.shard_map(lambda a: fn(a[0])[None], mesh=mesh,
+                          in_specs=(P(("pod", "data")),),
+                          out_specs=P(("pod", "data")))
         return np.asarray(f(x))
     h = run(lambda a: hierarchical_all_to_all(a, "data", "pod"))
     fl = run(lambda a: flat_all_to_all(a, "data", "pod"))
