@@ -450,66 +450,72 @@ def shuffle_device_body(vals: jax.Array, plan: HybridShufflePlan,
     key_off = jnp.arange(q_rack)
 
     # ---- Stage 1: cross-rack all_to_all over 'rack' ------------------------
-    table = jnp.zeros((n_layer, q_rack, d), vals.dtype)
-    my_keys = jax.lax.dynamic_slice_in_dim(vals, i * q_rack, q_rack, 1)
-    table = table.at[my_local].set(my_keys)          # locally mapped rows
-    if n_send > 0:
-        if coded:
-            # encode: gather the arity components of every packet of every
-            # destination stream — component c of packet m to rack z is a
-            # locally mapped row restricted to rack mcast_comp_rack[...,c]'s
-            # key block — then combine with f(.)
-            comp_pos = tables.mcast_comp_pos[i]      # [P, n_send, arity]
-            cols = (tables.mcast_comp_rack[i][..., None] * q_rack
-                    + key_off)                       # [P, n_send, ar, q_rack]
-            comps = vals[comp_pos[..., None], cols]  # [P, n_send, ar, qr, d]
-            blocks = _combine([comps[:, :, c] for c in range(arity)],
-                              multicast, combine_impl)
-        else:
-            my_send = tables.send_pos[i, j]          # [P, n_send]
+    # (each stage under a jax.named_scope, which the compiled program's op
+    # metadata carries: see repro.mapreduce.engine.fused_op_stages)
+    with jax.named_scope("stage1"):
+        table = jnp.zeros((n_layer, q_rack, d), vals.dtype)
+        my_keys = jax.lax.dynamic_slice_in_dim(vals, i * q_rack, q_rack, 1)
+        table = table.at[my_local].set(my_keys)      # locally mapped rows
+        if n_send > 0:
+            if coded:
+                # encode: gather the arity components of every packet of
+                # every destination stream — component c of packet m to rack
+                # z is a locally mapped row restricted to rack
+                # mcast_comp_rack[...,c]'s key block — then combine with f(.)
+                with jax.named_scope("encode"):
+                    comp_pos = tables.mcast_comp_pos[i]  # [P, n_send, ar]
+                    cols = (tables.mcast_comp_rack[i][..., None] * q_rack
+                            + key_off)               # [P, n_send, ar, qr]
+                    comps = vals[comp_pos[..., None], cols]  # [.., qr, d]
+                    blocks = _combine([comps[:, :, c] for c in range(arity)],
+                                      multicast, combine_impl)
+            else:
+                my_send = tables.send_pos[i, j]      # [P, n_send]
 
-            def build_block(z):
-                rows = jnp.take(vals, my_send[z], axis=0)   # [n_send, Q, d]
-                return jax.lax.dynamic_slice_in_dim(
-                    rows, key_starts[z], q_rack, 1)         # [n_send, qr, d]
-            blocks = jax.vmap(build_block)(jnp.arange(p.P))  # [P,n_send,qr,d]
-        recvd = jax.lax.all_to_all(blocks, "rack", split_axis=0,
-                                   concat_axis=0, tiled=True)
-        if coded:
-            # decode: subtract the arity-1 known components (rows this
-            # device mapped itself — the replicated-map side information)
-            recvd = recvd.reshape(p.P, n_send, q_rack, d)
-            kcols = (tables.mcast_known_rack[i][..., None] * q_rack
-                     + key_off)                      # [P, n_send, ar-1, qr]
-            known = vals[tables.mcast_known_pos[i][..., None], kcols]
-            recvd = _uncombine(recvd,
-                               [known[:, :, c] for c in range(arity - 1)],
-                               multicast, combine_impl)
-        my_recv = tables.recv_pos[i, j]
-        flat_dst = my_recv.reshape(-1)                   # [P*n_send]
-        flat_src = recvd.reshape(p.P * n_send, q_rack, d)
-        if tables.cross_valid is None:
-            # binomial: every slot from a distinct source rack is real
-            valid = (jnp.repeat(jnp.arange(p.P), n_send) != i)
-        elif tables.cross_valid.ndim == 4:
-            # degraded plans: per-LAYER validity (repair streams differ by
-            # which servers of the layer died)
-            valid = tables.cross_valid[i, j].reshape(-1)
-        else:
-            # families with padded streams (resolvable): per-slot mask
-            valid = tables.cross_valid[i].reshape(-1)
-        # the senders' shares are disjoint slices of each block, so target
-        # rows are hit at most once => add == set
-        table = table.at[flat_dst].add(
-            jnp.where(valid[:, None, None], flat_src, 0))
-    if patch is not None:
-        table = table + patch
+                def build_block(z):
+                    rows = jnp.take(vals, my_send[z], axis=0)  # [n_send,Q,d]
+                    return jax.lax.dynamic_slice_in_dim(
+                        rows, key_starts[z], q_rack, 1)        # [n_send,qr,d]
+                blocks = jax.vmap(build_block)(jnp.arange(p.P))
+            recvd = jax.lax.all_to_all(blocks, "rack", split_axis=0,
+                                       concat_axis=0, tiled=True)
+            if coded:
+                # decode: subtract the arity-1 known components (rows this
+                # device mapped itself — the replicated-map side information)
+                with jax.named_scope("decode"):
+                    recvd = recvd.reshape(p.P, n_send, q_rack, d)
+                    kcols = (tables.mcast_known_rack[i][..., None] * q_rack
+                             + key_off)              # [P, n_send, ar-1, qr]
+                    known = vals[tables.mcast_known_pos[i][..., None], kcols]
+                    recvd = _uncombine(
+                        recvd, [known[:, :, c] for c in range(arity - 1)],
+                        multicast, combine_impl)
+            my_recv = tables.recv_pos[i, j]
+            flat_dst = my_recv.reshape(-1)               # [P*n_send]
+            flat_src = recvd.reshape(p.P * n_send, q_rack, d)
+            if tables.cross_valid is None:
+                # binomial: every slot from a distinct source rack is real
+                valid = (jnp.repeat(jnp.arange(p.P), n_send) != i)
+            elif tables.cross_valid.ndim == 4:
+                # degraded plans: per-LAYER validity (repair streams differ
+                # by which servers of the layer died)
+                valid = tables.cross_valid[i, j].reshape(-1)
+            else:
+                # families with padded streams (resolvable): per-slot mask
+                valid = tables.cross_valid[i].reshape(-1)
+            # the senders' shares are disjoint slices of each block, so
+            # target rows are hit at most once => add == set
+            table = table.at[flat_dst].add(
+                jnp.where(valid[:, None, None], flat_src, 0))
+        if patch is not None:
+            table = table + patch
 
     # ---- Stage 2: intra-rack all_to_all over 'server' ----------------------
-    per_srv = table.reshape(n_layer, p.Kr, q_srv, d).transpose(1, 0, 2, 3)
-    gathered = jax.lax.all_to_all(per_srv, "server", split_axis=0,
-                                  concat_axis=0, tiled=True)
-    return gathered.reshape(p.Kr * n_layer, q_srv, d)
+    with jax.named_scope("stage2"):
+        per_srv = table.reshape(n_layer, p.Kr, q_srv, d).transpose(1, 0, 2, 3)
+        gathered = jax.lax.all_to_all(per_srv, "server", split_axis=0,
+                                      concat_axis=0, tiled=True)
+        return gathered.reshape(p.Kr * n_layer, q_srv, d)
 
 
 def hybrid_shuffle(values_local: jax.Array, plan: HybridShufflePlan,

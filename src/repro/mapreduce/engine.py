@@ -49,7 +49,11 @@ from ..core.resolvable import resolvable_assignment
 from ..core.shuffle_plan import count_plan, make_plan
 from ..obs.bytes import plan_rack_bytes, reconcile, record_rack_bytes
 from ..obs.metrics import refresh_cache_metrics
-from ..obs.tracing import get_tracer, spans_from_phase_timings
+from ..obs.tracing import get_tracer, op_stages, spans_from_phase_timings
+
+# the jax.named_scope names of the fused program's stages, in pipeline order
+# (map and reduce here; the shuffle's in shuffle_device_body)
+FUSED_STAGES = ("map", "stage1", "encode", "decode", "stage2", "reduce")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,7 +80,8 @@ class JobResult:
     intra_rack_bytes: float = 0.0
     cross_rack_bytes: float = 0.0
     # measured-wall-clock blame components (repro.obs.blame schema) from
-    # the run's engine_phase trace spans; None when tracing is disabled.
+    # the run's engine_phase trace spans, one per traced phase (upload,
+    # assemble and account included); None when tracing is disabled.
     # Components sum to the total traced phase wall (the engine-side
     # exactness law) — the fused device program stays one indivisible
     # 'map_shuffle_reduce' entry rather than a fabricated per-phase split
@@ -187,10 +192,12 @@ def _fused_executable(job: MapReduceJob, plan: HybridShufflePlan, mesh: Mesh,
     tables = device_plan_tables(plan)       # on-device constants, plan-cached
 
     def device_fn(subs):                    # [1, n_loc, ...subfile dims]
-        vals = jax.vmap(lambda s: job.map_fn(s, p.Q))(subs[0])  # [n_loc,Q,d]
+        with jax.named_scope("map"):
+            vals = jax.vmap(lambda s: job.map_fn(s, p.Q))(subs[0])
         rows = shuffle_device_body(vals, plan, tables, multicast,
                                    combine_impl)                # [N,q_srv,d]
-        return jax.vmap(job.reduce_fn, in_axes=1)(rows)[None]   # [1,q_srv,*]
+        with jax.named_scope("reduce"):
+            return jax.vmap(job.reduce_fn, in_axes=1)(rows)[None]
 
     # check_vma off for pallas: see coded_collectives.hybrid_shuffle
     fn = jax.shard_map(device_fn, mesh=mesh,
@@ -198,6 +205,40 @@ def _fused_executable(job: MapReduceJob, plan: HybridShufflePlan, mesh: Mesh,
                        out_specs=P(("rack", "server")),
                        check_vma=combine_impl != "pallas")
     return jax.jit(fn)
+
+
+# (fused executable, argument shape, dtype) -> the argument's
+# ShapeDtypeStruct with its sharding, recorded at the first call of each, for
+# fused_op_stages; at most as many as _fused_executable caches
+_FUSED_CALLS: Dict[tuple, jax.ShapeDtypeStruct] = {}
+
+
+def _record_fused_call(exe, arg: jax.Array) -> None:
+    key = (exe, arg.shape, arg.dtype)
+    if key not in _FUSED_CALLS:
+        if len(_FUSED_CALLS) >= _fused_executable.cache_info().maxsize:
+            del _FUSED_CALLS[next(iter(_FUSED_CALLS))]
+        _FUSED_CALLS[key] = jax.ShapeDtypeStruct(arg.shape, arg.dtype,
+                                                 sharding=arg.sharding)
+
+
+def fused_op_stages() -> Dict[str, str]:
+    """``{op key: stage}`` over every fused executable called so far: which
+    of :data:`FUSED_STAGES` (or joint ``"a+b"`` label) each op of the
+    compiled programs belongs to, keyed by :func:`repro.obs.tracing.op_key`
+    so that a device trace's op names look it up.  An op key found in two
+    different programs is left out.  Compiles each program again to read
+    its HLO text: for readers of a trace, never on the job path."""
+    texts = {exe.lower(spec).compile().as_text()
+             for (exe, _, _), spec in _FUSED_CALLS.items()}
+    table: Dict[str, str] = {}
+    twice: set = set()
+    for text in texts:
+        for key, stage in op_stages(text, FUSED_STAGES).items():
+            if key in table:
+                twice.add(key)
+            table[key] = stage
+    return {k: v for k, v in table.items() if k not in twice}
 
 
 def _blame_from_spans(events, cost) -> Dict[str, float] | None:
@@ -215,8 +256,8 @@ def _blame_from_spans(events, cost) -> Dict[str, float] | None:
     if not phases:
         return None
     comps: Dict[str, float] = {}
-    for k in ("plan_compile", "map", "pack", "reduce",
-              "map_shuffle_reduce"):
+    for k in ("plan_compile", "map", "pack", "upload", "reduce",
+              "map_shuffle_reduce", "assemble", "account"):
         if k in phases:
             comps[k] = phases[k]
     if "shuffle" in phases:
@@ -295,9 +336,13 @@ def run_job_distributed(job: MapReduceJob, subfiles: np.ndarray,
         with tracer.span("pack", kind="engine_phase", job=job.name):
             local_subs = put_per_device(
                 pack_local_subfiles(subfiles, plan), mesh)
+        if tracer.enabled:          # a wait only a traced run pays for
+            with tracer.span("upload", kind="engine_phase", job=job.name):
+                jax.block_until_ready(local_subs)
         with tracer.span("map_shuffle_reduce", kind="engine_phase",
                          job=job.name, fused="true"):
             exe = _fused_executable(job, plan, mesh, multicast, combine_impl)
+            _record_fused_call(exe, local_subs)
             out = exe(local_subs)                       # [K, q_srv, d_out]
             jax.block_until_ready(out)
     else:
@@ -313,18 +358,23 @@ def run_job_distributed(job: MapReduceJob, subfiles: np.ndarray,
             # [K, N, q_srv, d]; rows ordered by reduce_ready_order
             out = jax.vmap(jax.vmap(job.reduce_fn, in_axes=1))(shuffled)
             jax.block_until_ready(out)
-    final = assemble_outputs(out, plan)                 # [Q, d_out]
-    scheme = scheme_of_family(scheme_family)
-    c = (hybrid_resolvable_cost(p) if scheme_family == "resolvable"
-         else hybrid_cost(p))
-    # rack-level byte accounting off the ACTUAL compiled plan, paper-metric
-    # counting, re-reconciled against the closed form on every run
-    rb = record_rack_bytes(plan_rack_bytes(plan, "coded", job.d),
-                           scheme, scheme_family, layer="engine")
-    reconcile(rb.intra_total, rb.cross_total, p, scheme, d=job.d,
-              check=False)
-    # cache gauges stay current in snapshots without a manual pull
-    refresh_cache_metrics()
+    with tracer.span("assemble", kind="engine_phase", job=job.name):
+        final = assemble_outputs(out, plan)             # [Q, d_out]
+        if tracer.enabled:
+            jax.block_until_ready(final)
+    with tracer.span("account", kind="engine_phase", job=job.name):
+        scheme = scheme_of_family(scheme_family)
+        c = (hybrid_resolvable_cost(p) if scheme_family == "resolvable"
+             else hybrid_cost(p))
+        # rack-level byte accounting off the ACTUAL compiled plan,
+        # paper-metric counting, re-reconciled against the closed form on
+        # every run
+        rb = record_rack_bytes(plan_rack_bytes(plan, "coded", job.d),
+                               scheme, scheme_family, layer="engine")
+        reconcile(rb.intra_total, rb.cross_total, p, scheme, d=job.d,
+                  check=False)
+        # cache gauges stay current in snapshots without a manual pull
+        refresh_cache_metrics()
     return JobResult(final, c.intra, c.cross, scheme,
                      intra_rack_bytes=rb.intra_total,
                      cross_rack_bytes=rb.cross_total,

@@ -5,8 +5,10 @@ One event type serves all three layers:
   * the **simulator** replaces its bare ``(now, kind, tuple)`` trace entries
     with :class:`TraceEvent` (a compatibility shim on
     :class:`repro.sim.ClusterSim` keeps the legacy tuple view alive);
-  * the **engine** wraps its host-side phases (plan compile, host pack, the
-    jitted fused program) in spans via the process-global tracer, and
+  * the **engine** wraps its phases (plan compile, host pack, upload, the
+    jitted fused program, output assembly, byte accounting) in spans via the
+    process-global tracer, which also opens a profiler ``TraceAnnotation``
+    per span so the spans land on the device trace's clock, and
     :func:`spans_from_phase_timings` converts the calibrated per-phase
     device timings of ``measure_phase_timings`` into spans;
   * the **scheduler** emits admission / decision / drain events into the
@@ -33,9 +35,10 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import json
+import re
 import time
-from typing import (Any, Callable, Dict, Iterable, List, Optional, Tuple,
-                    Union)
+from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
+                    Tuple, Union)
 
 TS_NDIGITS = 12          # exporter-side rounding (float-stable artifacts)
 
@@ -100,12 +103,19 @@ class Tracer:
     attribute check), so instrumented hot paths cost nothing when tracing
     is off — the engine's process-global tracer ships disabled and is
     switched on per run/bench via :func:`enable_tracing`.
+
+    ``annotate=True`` (the process-global tracer only) makes every enabled
+    :meth:`span` also open a ``jax.profiler.TraceAnnotation`` named
+    ``<kind>:<phase>``, so a profiler trace taken meanwhile shows the span
+    on its host plane, on the device ops' clock.  Tracers on an injected
+    (simulated) clock never annotate.
     """
 
     def __init__(self, clock: Optional[Callable[[], float]] = None,
-                 enabled: bool = True) -> None:
+                 enabled: bool = True, annotate: bool = False) -> None:
         self.clock = clock if clock is not None else time.perf_counter
         self.enabled = enabled
+        self.annotate = annotate
         self.events: List[TraceEvent] = []
 
     def event(self, kind: str, job_id: Optional[int] = None,
@@ -136,21 +146,28 @@ class Tracer:
         if not self.enabled:
             yield self
             return
-        t0 = self.clock()
-        try:
-            yield self
-        finally:
-            self.span_at(t0, self.clock(), kind, job_id, phase, **labels)
+        with _annotation(f"{kind}:{phase}") if self.annotate else \
+                contextlib.nullcontext():
+            t0 = self.clock()
+            try:
+                yield self
+            finally:
+                self.span_at(t0, self.clock(), kind, job_id, phase, **labels)
 
     def clear(self) -> None:
         self.events.clear()
+
+
+def _annotation(name: str):
+    import jax.profiler            # lazily: this module works without JAX
+    return jax.profiler.TraceAnnotation(name)
 
 
 # ---------------------------------------------------------------------------
 # Process-global tracer (engine + anything without its own clock)
 # ---------------------------------------------------------------------------
 
-_TRACER = Tracer(enabled=False)
+_TRACER = Tracer(enabled=False, annotate=True)
 
 
 def get_tracer() -> Tracer:
@@ -204,6 +221,101 @@ def spans_from_phase_timings(row: Dict[str, Any],
     if tracer.enabled:
         tracer.events.extend(out)
     return out
+
+
+# ---------------------------------------------------------------------------
+# Device ops -> named stages, from a compiled module's HLO text
+# ---------------------------------------------------------------------------
+
+# "[ROOT ]%name = <result shape> opcode(": the part of an instruction that
+# both a compiled module's text and a device trace's op name print alike
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([^\s=]+) = (.*?) ([a-z][a-z0-9\-]*)\(")
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_APPLIES = re.compile(r"to_apply=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_HEADER = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+
+
+def op_key(text: str) -> Optional[str]:
+    """The key of one HLO instruction, from a line of module text or a
+    device trace's op name: ``"<name> = <result shape> <opcode>"`` with
+    layouts dropped (``%fusion.1 = f32[1000,1]{1,0:T(8,128)} fusion(s32[..``
+    -> ``"fusion.1 = f32[1000,1] fusion"``).  None for any other text."""
+    m = _INSTR.match(text)
+    if m is None:
+        return None
+    return f"{m.group(1)} = {_LAYOUT.sub('', m.group(2))} {m.group(3)}"
+
+
+def _operands(line: str, start: int) -> List[str]:
+    """Names of the operands in the parentheses opening at ``start``."""
+    depth = 0
+    for i in range(start, len(line)):
+        if line[i] == "(":
+            depth += 1
+        elif line[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return _OPERAND.findall(line, start, i)
+    return []
+
+
+def op_stages(hlo_text: str, stages: Sequence[str]) -> Dict[str, str]:
+    """``{op_key: stage}`` for the instructions of a compiled module, by
+    the ``stages`` (``jax.named_scope`` names) their metadata names.
+
+    An instruction's scope paths are the lists of ``stages`` names in its
+    ``op_name``, outermost first, one per ``;``-joined part (the compiler
+    joins the names of instructions it merges); a fusion's paths are its
+    own and those of every instruction in the computations it calls.
+    Paths that another path extends are dropped, and the innermost name of
+    each path left is a stage of the instruction.  An instruction whose metadata names no stage
+    (the compiler drops it on some rewrites, e.g. the TPU's scatter) takes
+    the stages of the instructions whose results it reads.  One stage is
+    the label; more give the joint label ``"a+b"`` in ``stages`` order.
+    Instructions inside fused computations and reducers get no key of their
+    own: a device trace never shows them."""
+    comps: Dict[str, list] = {}          # computation -> its instructions
+    body: list = []
+    for line in hlo_text.splitlines():
+        head = _HEADER.match(line)
+        if head:
+            body = comps.setdefault(head.group(1), [])
+            continue
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name = _OP_NAME.search(line)
+        own = {tuple(c for c in part.split("/") if c in stages)
+               for part in (name.group(1) if name else "").split(";")}
+        body.append((m.group(1), op_key(line), own - {()},
+                     _CALLS.findall(line), _operands(line, m.end() - 1)))
+
+    def paths(own, calls):
+        out = set(own)
+        for c in calls:
+            for _, _, p, cc, _ in comps.get(c, ()):
+                out |= paths(p, cc)
+        return out
+
+    fused = {c for instrs in comps.values() for *_, cs, _ in instrs
+             for c in cs} | set(_APPLIES.findall(hlo_text))
+    table: Dict[str, str] = {}
+    for comp, instrs in comps.items():
+        if comp in fused:
+            continue
+        of: Dict[str, set] = {}            # instruction -> its stages
+        for name, key, own, calls, operands in instrs:
+            found = paths(own, calls)
+            inner = {p[-1] for p in found
+                     if not any(q[:len(p)] == p and q != p for q in found)}
+            of[name] = inner or set().union(*(of.get(o, set())
+                                              for o in operands))
+            if of[name]:
+                table[key] = "+".join(s for s in stages if s in of[name])
+    return table
 
 
 # ---------------------------------------------------------------------------
@@ -288,6 +400,6 @@ def validate_chrome_trace(doc: Union[Dict[str, Any], str]) -> int:
 
 __all__ = [
     "TraceEvent", "Tracer", "get_tracer", "enable_tracing",
-    "spans_from_phase_timings", "to_jsonl", "to_chrome_trace",
-    "validate_chrome_trace", "TS_NDIGITS",
+    "spans_from_phase_timings", "op_key", "op_stages", "to_jsonl",
+    "to_chrome_trace", "validate_chrome_trace", "TS_NDIGITS",
 ]

@@ -7,6 +7,7 @@ silently falls back to the interpreter, and a one-chip program that does
 not fit HBM.  Nothing runs; only compiles.
 """
 import os
+import pathlib
 
 import numpy as np
 import pytest
@@ -20,7 +21,8 @@ from repro.core.coded_collectives import compile_hybrid_plan
 from repro.core.params import SchemeParams
 from repro.kernels.coded_combine import kernel, ops
 from repro.mapreduce import engine
-from repro.mapreduce.jobs import wide_histogram_job
+from repro.mapreduce.jobs import histogram_job, wide_histogram_job
+from repro.obs.tracing import op_key, op_stages
 
 V5E_HBM_BYTES = 15.75 * 2**30          # usable HBM of one v5e chip
 
@@ -119,3 +121,81 @@ def test_one_chip_engine_fits_hbm(topo, no_compile_cache):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes == p.N * (1 << 20) * 4
     assert total < V5E_HBM_BYTES, total
+
+
+def _entry_ops(text):
+    lines = text[text.index("\nENTRY "):].splitlines()[2:]
+    return lines[:lines.index("}")]
+
+
+def test_wordcount_program_stages_for_v5e(topo, no_compile_cache):
+    """The one-chip word-count program (HiBench small, 2^25 word ids) as
+    the chip's compiler builds it: its scatter-add, a kCustom fusion whose
+    metadata the compiler drops, still reads as the map."""
+    p = SchemeParams(K=1, P=1, Q=1000, N=32, r=1)
+    mesh = _mesh(topo, (1, 1))
+    x = jax.ShapeDtypeStruct((1, p.N, 1 << 20), jnp.int32,
+                             sharding=NamedSharding(mesh,
+                                                    P(("rack", "server"))))
+    exe = engine._fused_executable(histogram_job(), compile_hybrid_plan(p),
+                                   mesh, "unicast", "xla")
+    text = exe.lower(x).compile().as_text()
+    table = op_stages(text, engine.FUSED_STAGES)
+    ops = {op_key(line): line for line in _entry_ops(text)}
+    scatter, = [k for k, line in ops.items() if "kind=kCustom" in line
+                and "op_name=" not in line]
+    assert table[scatter] == "map"
+    assert table["reduce.3 = f32[1000] reduce"] == "reduce"
+
+
+def test_coded_program_stages_for_v5e(topo, no_compile_cache, monkeypatch):
+    """The four-chip coded program with the Pallas codec, as the chip's
+    compiler builds it: the exchange is stage 1, the kernels encode and
+    decode."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    p = SchemeParams(K=4, P=4, Q=1024, N=96, r=2)
+    mesh = _mesh(topo, (4, 1))
+    plan = compile_hybrid_plan(p)
+    n_loc = plan.local_subfiles.reshape(p.K, -1).shape[1]
+    x = jax.ShapeDtypeStruct((p.K, n_loc, 1 << 18), jnp.int32,
+                             sharding=NamedSharding(mesh,
+                                                    P(("rack", "server"))))
+    exe = engine._fused_executable(wide_histogram_job(2048), plan, mesh,
+                                   "coded", "pallas")
+    text = exe.lower(x).compile().as_text()
+    table = op_stages(text, engine.FUSED_STAGES)
+    labels = {}
+    for line in _entry_ops(text):
+        key = op_key(line)
+        for what in (" all-to-all", " custom-call"):
+            if key.endswith(what):
+                labels.setdefault(key.split(".")[0] + what, set()).add(
+                    table.get(key))
+    assert labels == {"all_to_all all-to-all": {"stage1"},
+                      "coded_encode custom-call": {"encode"},
+                      "coded_decode custom-call": {"decode"}}
+    # the same program, traced on four v5e chips (a kept trace of three
+    # jobs): the keys of the compile here name the chip's ops
+    busy = _fused_op_seconds(FOUR_CHIP_TRACE, "jit_device_fn(")
+    labelled = sum(t for op, t in busy.items() if op_key(op) in table)
+    assert labelled >= 0.99 * sum(busy.values()) > 0
+
+
+FOUR_CHIP_TRACE = (pathlib.Path(__file__).parent / "chipbench"
+                   / "wide_hist_coded_4chip.xplane.pb")
+
+
+def _fused_op_seconds(xplane, module):
+    """Device seconds by op name of the ops run inside ``module``'s runs,
+    summed over the chips of a kept profiler trace."""
+    from jax.profiler import ProfileData
+    out = {}
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        lines = {line.name: list(line.events) for line in plane.lines}
+        runs = [(e.start_ns, e.start_ns + e.duration_ns)
+                for e in lines.get("XLA Modules", ())
+                if e.name.startswith(module)]
+        for e in lines.get("XLA Ops", ()):
+            if any(a <= e.start_ns < b for a, b in runs):
+                out[e.name] = out.get(e.name, 0.0) + e.duration_ns / 1e9
+    return out
