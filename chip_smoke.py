@@ -7,14 +7,17 @@ compiles and gives bit-exact answers on the chip.
 
 One chip: ``run_job_distributed`` on a (1, 1) ('rack', 'server') mesh,
 N=512 subfiles x 2^20 int32 tokens (2 GiB) -> Q=1024 keys x d=128, twice
-(cold, then warm), bit-exact against ``run_job``; then the coded_combine
-Pallas kernels at shuffle widths, bit-exact against the XLA combine, each
-checked to hold a compiled Mosaic kernel (``tpu_custom_call``).
+(cold, then warm), bit-exact against ``run_job`` and against a NumPy
+``bincount`` of the tokens; then the Pallas kernels (the coded_combine
+codec at shuffle widths against the XLA combine, the bucket count against
+the scatter-add), each bit-exact and checked to hold a compiled Mosaic
+kernel (``tpu_custom_call``).
 
 Four chips: the fused engine on meshes (2, 2) and (4, 1) at every r the
 mesh admits, under unicast and coded multicast with the XLA and Pallas
 combines (plus one GF(2) coded_xor case), each bit-exact against
-``run_job`` on one device.
+``run_job`` on one device, which is checked against NumPy.  Both modes
+print how many programs counted their keys on the MXU kernel.
 
 Every phase runs in this one process, which holds the chip(s).  Without a
 TPU the script exits non-zero before doing anything.  The last line of
@@ -78,11 +81,28 @@ def _tokens(seed: int, n: int, t: int):
         0, 1 << 31, size=(n, t), dtype=np.int32)
 
 
+def _numpy_wide_histogram(subfiles, Q: int, d: int, dtype):
+    """``wide_histogram_job(d, dtype)``'s outputs, from a NumPy bincount
+    of every token's key."""
+    import numpy as np
+    keys = subfiles.reshape(-1).view(np.uint32) % np.uint32(Q)
+    counts = np.bincount(keys, minlength=Q).astype(dtype)
+    w = (np.arange(d, dtype=dtype) % 7) + 1
+    return counts[:, None] * w[None, :]
+
+
+def _programs_on_mxu() -> int:
+    from repro.obs import metrics
+    return int(metrics.counter("bucket_count_programs_total").value(
+        impl="mxu"))
+
+
 def engine_phase(seed: int, N: int = 512, tokens: int = 1 << 20,
                  Q: int = 1024, d: int = 128) -> None:
     """One chip: K=P=1, r=1 — map, (size-1) shuffle, reduce, assembly."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
     from repro.core.params import SchemeParams
     from repro.mapreduce.engine import run_job, run_job_distributed
     from repro.mapreduce.jobs import wide_histogram_job
@@ -109,8 +129,10 @@ def engine_phase(seed: int, N: int = 512, tokens: int = 1 << 20,
     ref, ref_s = _timed(lambda: jax.block_until_ready(
         run_job(job, jnp.asarray(subfiles), params).outputs))
     _assert_same(out, ref, "engine vs run_job")
+    _assert_same(out, _numpy_wide_histogram(subfiles, Q, d, np.float32),
+                 "engine vs numpy")
     print(f"engine: outputs {tuple(out.shape)} {out.dtype} bit-exact vs "
-          f"run_job ({ref_s:.3f} s)")
+          f"run_job ({ref_s:.3f} s) and numpy bincount")
 
 
 def kernel_phase(seed: int, T: int = 4096) -> None:
@@ -119,6 +141,7 @@ def kernel_phase(seed: int, T: int = 4096) -> None:
     import jax.numpy as jnp
     import numpy as np
     from repro.core.coded_collectives import _combine, _uncombine
+    from repro.kernels.bucket_count import ops as bc_ops, ref as bc_ref
     from repro.kernels.coded_combine import ops
 
     rng = np.random.default_rng(seed + 1)
@@ -146,6 +169,15 @@ def kernel_phase(seed: int, T: int = 4096) -> None:
                 _assert_same(got, want, f"{name} r={r} d={d}")
                 print(f"kernel: {name} r={r} T={T} d={d}: tpu_custom_call, "
                       f"bit-exact vs xla combine")
+    for Q in (1000, 4097):
+        keys = jnp.asarray(rng.integers(0, Q, size=(3, 64 * T)), jnp.int32)
+        _assert_mosaic(jax.jit(lambda b, Q=Q: bc_ops.bucket_counts_mxu(b, Q)),
+                       (keys,), f"bucket_count Q={Q}")
+        got = jax.block_until_ready(bc_ops.bucket_counts_mxu(keys, Q))
+        _assert_same(got, bc_ref.scatter_counts(keys, Q),
+                     f"bucket_count Q={Q}")
+        print(f"kernel: bucket_count Q={Q} T={64 * T} x 3: tpu_custom_call, "
+              f"bit-exact vs scatter-add")
 
 
 def four_chip_phase(seed: int, N: int = 96, tokens: int = 1 << 18,
@@ -153,6 +185,7 @@ def four_chip_phase(seed: int, N: int = 96, tokens: int = 1 << 18,
     """The fused two-stage shuffle across chips, every case vs run_job."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
     from repro.core.params import SchemeParams
     from repro.mapreduce.engine import run_job, run_job_distributed
     from repro.mapreduce.jobs import wide_histogram_job
@@ -163,6 +196,9 @@ def four_chip_phase(seed: int, N: int = 96, tokens: int = 1 << 18,
     ref = {k: jax.block_until_ready(run_job(
         job, jnp.asarray(subfiles), SchemeParams(K=1, P=1, Q=Q, N=N, r=1)
     ).outputs) for k, job in jobs.items()}
+    for k, dtype in (("f32", np.float32), ("i32", np.int32)):
+        _assert_same(ref[k], _numpy_wide_histogram(subfiles, Q, d, dtype),
+                     f"run_job {k} vs numpy")
     print(f"four-chip: input {N} subfiles x {tokens} int32, Q={Q}, d={d}; "
           f"run_job reference on one device")
     compile_s = _compile_seconds()
@@ -214,6 +250,7 @@ def main() -> None:
         kernel_phase(args.seed)
     else:
         four_chip_phase(args.seed)
+    print(f"bucket count: {_programs_on_mxu()} program(s) on the MXU kernel")
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(devices)}}))

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..kernels.bucket_count.ops import bucket_counts
 from .engine import MapReduceJob
 
 
@@ -12,7 +13,7 @@ def histogram_job(vocab_hash_mod: int = 2**16) -> MapReduceJob:
     value = occurrence count in the subfile.  Reduce = total count."""
     def map_fn(tokens: jax.Array, Q: int) -> jax.Array:
         bucket = (tokens.astype(jnp.uint32) % jnp.uint32(Q)).astype(jnp.int32)
-        counts = jnp.zeros((Q,), jnp.int32).at[bucket].add(1)
+        counts = bucket_counts(bucket, Q)
         return counts[:, None].astype(jnp.float32)          # [Q, 1]
 
     def reduce_fn(vals: jax.Array) -> jax.Array:            # [N, 1]
@@ -47,7 +48,7 @@ def wide_histogram_job(d: int, dtype=jnp.float32) -> MapReduceJob:
     """
     def map_fn(tokens: jax.Array, Q: int) -> jax.Array:
         bucket = (tokens.astype(jnp.uint32) % jnp.uint32(Q)).astype(jnp.int32)
-        counts = jnp.zeros((Q,), dtype).at[bucket].add(1)
+        counts = bucket_counts(bucket, Q, dtype)
         w = (jnp.arange(d, dtype=dtype) % 7) + 1
         return counts[:, None] * w[None, :]                  # [Q, d]
 

@@ -19,6 +19,8 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.core.coded_collectives import compile_hybrid_plan
 from repro.core.params import SchemeParams
+from repro.kernels.bucket_count import (kernel as bc_kernel,
+                                       ops as bc_ops)
 from repro.kernels.coded_combine import kernel, ops
 from repro.mapreduce import engine
 from repro.mapreduce.jobs import histogram_job, wide_histogram_job
@@ -106,17 +108,59 @@ def test_fused_engine_pallas_compiles_to_mosaic(topo, no_compile_cache,
     assert "all-to-all" in text
 
 
-def test_one_chip_engine_fits_hbm(topo, no_compile_cache):
+@pytest.mark.parametrize("mesh_shape,r,multicast,impl", [
+    ((2, 2), 1, "unicast", "xla"), ((4, 1), 2, "coded", "pallas")])
+def test_four_chip_engine_counts_on_mosaic(topo, no_compile_cache,
+                                           monkeypatch, mesh_shape, r,
+                                           multicast, impl):
+    """The four-chip programs of the smoke run with the bucket count on the
+    MXU kernel, inside a shard_map that checks varying axes (XLA combine)
+    and one that does not (Pallas combine)."""
+    monkeypatch.setattr(bc_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    p = SchemeParams(K=4, P=mesh_shape[0], Q=1024, N=96, r=r)
+    mesh = _mesh(topo, mesh_shape)
+    plan = compile_hybrid_plan(p)
+    n_loc = plan.local_subfiles.reshape(p.K, -1).shape[1]
+    x = jax.ShapeDtypeStruct((p.K, n_loc, 1 << 18), jnp.int32,
+                             sharding=NamedSharding(mesh,
+                                                    P(("rack", "server"))))
+    exe = engine._fused_executable(wide_histogram_job(2048), plan, mesh,
+                                   multicast, impl)
+    text = exe.lower(x).compile().as_text()
+    table = op_stages(text, engine.FUSED_STAGES)
+    assert any(table.get(op_key(line)) == "map"
+               for line in _entry_ops(text) if "tpu_custom_call" in line)
+
+
+def test_one_chip_engine_fits_hbm(topo, no_compile_cache, monkeypatch):
     """The one-chip smoke program (K=1, 2 GiB of input) fits one v5e."""
+    monkeypatch.setattr(bc_ops, "_on_tpu", lambda: True)
     p = SchemeParams(K=1, P=1, Q=1024, N=512, r=1)
+    _assert_fits_hbm(topo, wide_histogram_job(128), p)
+
+
+def test_wordcount_engine_fits_hbm(topo, no_compile_cache, monkeypatch):
+    """The benchmark's word count (Q=1000, 2^25 ids) fits one v5e with the
+    kernel's input laid out in rows of 128 ids."""
+    monkeypatch.setattr(bc_ops, "_on_tpu", lambda: True)
+    p = SchemeParams(K=1, P=1, Q=1000, N=32, r=1)
+    _assert_fits_hbm(topo, histogram_job(), p)
+
+
+def _assert_fits_hbm(topo, job, p):
+    """The one-chip fused program for N subfiles of 2^20 ids, its map
+    counting on the Mosaic kernel: the arguments and the whole program fit
+    one v5e."""
     mesh = _mesh(topo, (1, 1))
     plan = compile_hybrid_plan(p)
     x = jax.ShapeDtypeStruct((1, p.N, 1 << 20), jnp.int32,
                              sharding=NamedSharding(mesh,
                                                     P(("rack", "server"))))
-    exe = engine._fused_executable(wide_histogram_job(128), plan, mesh,
-                                   "unicast", "xla")
-    mem = exe.lower(x).compile().memory_analysis()
+    exe = engine._fused_executable(job, plan, mesh, "unicast", "xla")
+    compiled = exe.lower(x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes == p.N * (1 << 20) * 4
@@ -128,10 +172,13 @@ def _entry_ops(text):
     return lines[:lines.index("}")]
 
 
-def test_wordcount_program_stages_for_v5e(topo, no_compile_cache):
+def test_wordcount_program_stages_for_v5e(topo, no_compile_cache,
+                                          monkeypatch):
     """The one-chip word-count program (HiBench small, 2^25 word ids) as
-    the chip's compiler builds it: its scatter-add, a kCustom fusion whose
-    metadata the compiler drops, still reads as the map."""
+    the chip's compiler builds it: the map counts its keys in the Mosaic
+    bucket-count kernel, labelled ``map``, and no scatter-add is left in
+    the map (the only scatter is the stage-1 table fill)."""
+    monkeypatch.setattr(bc_ops, "_on_tpu", lambda: True)
     p = SchemeParams(K=1, P=1, Q=1000, N=32, r=1)
     mesh = _mesh(topo, (1, 1))
     x = jax.ShapeDtypeStruct((1, p.N, 1 << 20), jnp.int32,
@@ -141,11 +188,27 @@ def test_wordcount_program_stages_for_v5e(topo, no_compile_cache):
                                    mesh, "unicast", "xla")
     text = exe.lower(x).compile().as_text()
     table = op_stages(text, engine.FUSED_STAGES)
-    ops = {op_key(line): line for line in _entry_ops(text)}
-    scatter, = [k for k, line in ops.items() if "kind=kCustom" in line
-                and "op_name=" not in line]
-    assert table[scatter] == "map"
-    assert table["reduce.3 = f32[1000] reduce"] == "reduce"
+    entry = _entry_ops(text)
+    kernels = [op_key(line) for line in entry if "tpu_custom_call" in line]
+    assert len(kernels) == 1 and table[kernels[0]] == "map"
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert scatters and all('op_name="jit(device_fn)/stage1/' in line
+                            for line in scatters)
+    assert not any("kind=kCustom" in line and table.get(op_key(line)) ==
+                   "map" for line in entry)
+    reduce, = [op_key(line) for line in entry if " reduce(" in line]
+    assert table[reduce] == "reduce"
+
+
+def test_bucket_count_compiles_at_max_q(one_chip):
+    """MAX_Q, the largest key range the engine sends to the kernel, fits
+    the chip's scoped VMEM."""
+    Q = bc_kernel.MAX_Q
+    x = jax.ShapeDtypeStruct((2, 1024, bc_kernel.LANES), jnp.int32,
+                             sharding=one_chip)
+    compiled = jax.jit(lambda a: bc_kernel.bucket_counts_pallas(
+        a, Q, interpret=False)).lower(x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_coded_program_stages_for_v5e(topo, no_compile_cache, monkeypatch):

@@ -5,6 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.bucket_count import (kernel as bc_kernel, ops as bc_ops,
+                                       ref as bc_ref)
 from repro.kernels.coded_combine import ops as cc_ops, ref as cc_ref
 from repro.kernels.flash_attention import ops as fa_ops, ref as fa_ref
 from repro.kernels.rwkv_scan import ops as rw_ops, ref as rw_ref
@@ -180,3 +182,71 @@ def test_wkv_scan_vs_naive_steps():
                                rtol=3e-4, atol=3e-4)
     np.testing.assert_allclose(np.asarray(sT), np.asarray(sref),
                                rtol=3e-4, atol=3e-4)
+
+
+# ---------------------------------------------------------------------------
+# bucket_count
+# ---------------------------------------------------------------------------
+
+# three id blocks, the last one partial and T not a multiple of 128
+BC_T = 2 * bc_kernel.BLOCK_ROWS * bc_kernel.LANES + 333
+
+
+def _bucket_keys(mix, Q, shape, seed):
+    rng = np.random.default_rng(seed)
+    if mix == "uniform":
+        keys = rng.integers(0, Q, shape)
+    elif mix == "one_key":          # every id of a row in one key
+        keys = np.full(shape, Q - 1)
+    else:
+        keys = (rng.zipf(1.3, shape) - 1) % Q
+    return keys.astype(np.int32)
+
+
+@pytest.mark.parametrize("Q", [1, 7, 128, 1000, 1024, 4097])
+@pytest.mark.parametrize("mix", ["uniform", "one_key", "zipf"])
+def test_bucket_counts_vs_bincount(Q, mix):
+    """Exact int32 counts, vmapped over a batch of rows, whatever the skew:
+    one key holding all of a row's ids sums its per-block f32 partials in
+    int32 across the three blocks."""
+    keys = _bucket_keys(mix, Q, (2, BC_T), seed=Q)
+    got = jax.vmap(lambda b: bc_ops.bucket_counts_mxu(b, Q))(
+        jnp.asarray(keys))
+    assert got.dtype == jnp.int32
+    want = np.stack([np.bincount(row, minlength=Q) for row in keys])
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("shape", [(1,), (5,), (2, 3, 130), (4, 1, 1024)])
+def test_bucket_counts_leading_dims_and_short_rows(shape):
+    keys = _bucket_keys("uniform", 300, shape, seed=len(shape))
+    got = np.asarray(bc_ops.bucket_counts_mxu(jnp.asarray(keys), 300))
+    want = np.asarray(bc_ref.scatter_counts(jnp.asarray(keys), 300))
+    assert got.shape == shape[:-1] + (300,)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_bucket_counts_path_and_program_counter(monkeypatch):
+    """Off TPU the helper keeps the scatter; on TPU (steered here) it takes
+    the kernel up to MAX_Q.  The counter counts traces, not calls."""
+    from repro.obs import metrics
+    metrics.reset()
+    programs = metrics.counter("bucket_count_programs_total")
+    x = jnp.arange(3000, dtype=jnp.int32) % 7
+    f = jax.jit(lambda b: bc_ops.bucket_counts(b, 7, jnp.float32))
+    for _ in range(2):
+        out = f(x)
+        assert out.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.bincount(np.asarray(x)))
+    assert (programs.value(impl="scatter"), programs.value(impl="mxu")) \
+        == (1, 0)
+
+    monkeypatch.setattr(bc_ops, "_on_tpu", lambda: True)
+    jaxpr = str(jax.make_jaxpr(lambda b: bc_ops.bucket_counts(b, 7))(x))
+    assert "pallas_call" in jaxpr and "scatter" not in jaxpr
+    big = bc_kernel.MAX_Q + 1                   # past the VMEM limit
+    jaxpr = str(jax.make_jaxpr(lambda b: bc_ops.bucket_counts(b, big))(x))
+    assert "pallas_call" not in jaxpr and "scatter-add" in jaxpr
+    assert (programs.value(impl="scatter"), programs.value(impl="mxu")) \
+        == (2, 1)
