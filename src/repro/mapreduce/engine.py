@@ -24,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Callable, Dict, List
+from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
@@ -47,13 +47,16 @@ from ..core.params import SchemeParams
 from ..core.plan_registry import scheme_of_family
 from ..core.resolvable import resolvable_assignment
 from ..core.shuffle_plan import count_plan, make_plan
-from ..obs.bytes import plan_rack_bytes, reconcile, record_rack_bytes
+from ..obs.bytes import (plan_rack_bytes, reconcile, record_rack_bytes,
+                         record_shuffle_slots)
 from ..obs.metrics import refresh_cache_metrics
 from ..obs.tracing import get_tracer, op_stages, spans_from_phase_timings
 
 # the jax.named_scope names of the fused program's stages, in pipeline order
-# (map and reduce here; the shuffle's in shuffle_device_body)
-FUSED_STAGES = ("map", "stage1", "encode", "decode", "stage2", "reduce")
+# (map and reduce here; the shuffle's in shuffle_device_body; a job's
+# reduce_fn may open reducer_sort inside reduce, as terasort_job does)
+FUSED_STAGES = ("map", "stage1", "encode", "decode", "stage2", "reduce",
+                "reducer_sort")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,6 +65,9 @@ class MapReduceJob:
     d: int                                    # payload width per (key, subfile)
     map_fn: Callable[[jax.Array, int], jax.Array]   # subfile data -> [Q, d]
     reduce_fn: Callable[[jax.Array], jax.Array]     # [N, d] -> [d_out]
+    # a job whose map emits fixed-capacity blocks of record slots: the
+    # input's shape -> (record slots, padding slots) a job ships
+    record_slots: Callable[[tuple], Tuple[int, int]] | None = None
 
 
 @dataclasses.dataclass
@@ -211,6 +217,8 @@ def _fused_executable(job: MapReduceJob, plan: HybridShufflePlan, mesh: Mesh,
 # ShapeDtypeStruct with its sharding, recorded at the first call of each, for
 # fused_op_stages; at most as many as _fused_executable caches
 _FUSED_CALLS: Dict[tuple, jax.ShapeDtypeStruct] = {}
+# the compiled HLO text of each recorded program, by the same key
+_FUSED_TEXTS: Dict[tuple, str] = {}
 
 
 def _record_fused_call(exe, arg: jax.Array) -> None:
@@ -222,15 +230,23 @@ def _record_fused_call(exe, arg: jax.Array) -> None:
                                                  sharding=arg.sharding)
 
 
+def _fused_text(key: tuple, spec: jax.ShapeDtypeStruct) -> str:
+    """The compiled HLO text of one recorded program, compiled once."""
+    if key not in _FUSED_TEXTS:
+        for old in [k for k in _FUSED_TEXTS if k not in _FUSED_CALLS]:
+            del _FUSED_TEXTS[old]
+        _FUSED_TEXTS[key] = key[0].lower(spec).compile().as_text()
+    return _FUSED_TEXTS[key]
+
+
 def fused_op_stages() -> Dict[str, str]:
     """``{op key: stage}`` over every fused executable called so far: which
     of :data:`FUSED_STAGES` (or joint ``"a+b"`` label) each op of the
     compiled programs belongs to, keyed by :func:`repro.obs.tracing.op_key`
     so that a device trace's op names look it up.  An op key found in two
-    different programs is left out.  Compiles each program again to read
-    its HLO text: for readers of a trace, never on the job path."""
-    texts = {exe.lower(spec).compile().as_text()
-             for (exe, _, _), spec in _FUSED_CALLS.items()}
+    different programs is left out.  Compiles each program again, once, to
+    read its HLO text: for readers of a trace, never on the job path."""
+    texts = {_fused_text(key, spec) for key, spec in _FUSED_CALLS.items()}
     table: Dict[str, str] = {}
     twice: set = set()
     for text in texts:
@@ -373,6 +389,9 @@ def run_job_distributed(job: MapReduceJob, subfiles: np.ndarray,
                                scheme, scheme_family, layer="engine")
         reconcile(rb.intra_total, rb.cross_total, p, scheme, d=job.d,
                   check=False)
+        if job.record_slots is not None:
+            record_shuffle_slots(job.name,
+                                 *job.record_slots(np.shape(subfiles)))
         # cache gauges stay current in snapshots without a manual pull
         refresh_cache_metrics()
     return JobResult(final, c.intra, c.cross, scheme,

@@ -171,8 +171,23 @@ def record_rack_bytes(rb: RackBytes, scheme: str, family: str = "",
     return rb
 
 
+def record_shuffle_slots(job: str, records: int, padding: int,
+                         reg: Optional[_metrics.MetricsRegistry] = None
+                         ) -> None:
+    """Record one job's map-output record slots in
+    ``shuffle_record_slots_total{job, kind=record|padding}``: the slots
+    that carry a record and those that pad a fixed-capacity block (they
+    cross the wire all the same)."""
+    reg = reg if reg is not None else _metrics.registry()
+    slots = reg.counter("shuffle_record_slots_total",
+                        "map-output record slots shipped, records vs "
+                        "padding")
+    slots.inc(records, job=job, kind="record")
+    slots.inc(padding, job=job, kind="padding")
+
+
 __all__ = [
     "RackBytes", "ByteReconciliationError", "plan_rack_bytes",
     "degraded_rack_bytes", "closed_form_bytes", "reconcile",
-    "record_rack_bytes",
+    "record_rack_bytes", "record_shuffle_slots",
 ]

@@ -236,6 +236,8 @@ _CALLS = re.compile(r"calls=%?([\w.\-]+)")
 _APPLIES = re.compile(r"to_apply=%?([\w.\-]+)")
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _HEADER = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+# a scope inside a transform is named through it: "vmap(reducer_sort)"
+_TRANSFORMED = re.compile(r"^(?:[\w\-]+\()+([^()]*)\)+$")
 
 
 def op_key(text: str) -> Optional[str]:
@@ -262,12 +264,20 @@ def _operands(line: str, start: int) -> List[str]:
     return []
 
 
+def _scope(component: str) -> str:
+    """A name-stack component with its transform wrappers dropped."""
+    m = _TRANSFORMED.match(component)
+    return m.group(1) if m else component
+
+
 def op_stages(hlo_text: str, stages: Sequence[str]) -> Dict[str, str]:
     """``{op_key: stage}`` for the instructions of a compiled module, by
     the ``stages`` (``jax.named_scope`` names) their metadata names.
 
     An instruction's scope paths are the lists of ``stages`` names in its
-    ``op_name``, outermost first, one per ``;``-joined part (the compiler
+    ``op_name``, outermost first, a scope opened inside a transform counting
+    by its own name (``vmap(reducer_sort)`` is ``reducer_sort``), one per
+    ``;``-joined part (the compiler
     joins the names of instructions it merges); a fusion's paths are its
     own and those of every instruction in the computations it calls.
     Paths that another path extends are dropped, and the innermost name of
@@ -288,7 +298,7 @@ def op_stages(hlo_text: str, stages: Sequence[str]) -> Dict[str, str]:
         if m is None:
             continue
         name = _OP_NAME.search(line)
-        own = {tuple(c for c in part.split("/") if c in stages)
+        own = {tuple(c for c in map(_scope, part.split("/")) if c in stages)
                for part in (name.group(1) if name else "").split(";")}
         body.append((m.group(1), op_key(line), own - {()},
                      _CALLS.findall(line), _operands(line, m.end() - 1)))

@@ -262,3 +262,39 @@ def _fused_op_seconds(xplane, module):
             if any(a <= e.start_ns < b for a, b in runs):
                 out[e.name] = out.get(e.name, 0.0) + e.duration_ns / 1e9
     return out
+
+
+def test_terasort_program_stages_for_v5e(topo, no_compile_cache,
+                                         monkeypatch):
+    """The four-chip TeraSort program of ``terasort.coded.4chip`` (mesh
+    (4, 1), r = 2, XOR-coded stage 1 on the Pallas codec), at a cut record
+    count, as the chip's compiler builds it: the codec is the Mosaic
+    kernels labelled ``encode`` and ``decode``, the exchange is stage 1,
+    the map partitions with a sort and no scatter, and the reducer's sort
+    is labelled ``reducer_sort``."""
+    from repro.mapreduce.jobs import terasort_job
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    p = SchemeParams(K=4, P=4, Q=32, N=24, r=2)
+    mesh = _mesh(topo, (4, 1))
+    plan = compile_hybrid_plan(p)
+    n_loc = plan.local_subfiles.reshape(p.K, -1).shape[1]
+    x = jax.ShapeDtypeStruct((p.K, n_loc, 1024 * 25), jnp.int32,
+                             sharding=NamedSharding(mesh,
+                                                    P(("rack", "server"))))
+    exe = engine._fused_executable(terasort_job(1024, 32, 64), plan, mesh,
+                                   "coded_xor", "pallas")
+    text = exe.lower(x).compile().as_text()
+    table = op_stages(text, engine.FUSED_STAGES)
+    labels = {}
+    for line in _entry_ops(text):
+        key = op_key(line)
+        for what in (" all-to-all", " custom-call", " sort", " scatter"):
+            if key.endswith(what) and table.get(key):
+                labels.setdefault(what.strip(), set()).add(table[key])
+    assert labels["all-to-all"] == {"stage1"}
+    assert {"encode", "decode"} <= labels["custom-call"]
+    assert labels["sort"] == {"map", "reducer_sort"}
+    assert "scatter" not in labels or "map" not in labels["scatter"]
+    kernels = {op_key(line).split(" = ")[0].rsplit(".", 1)[0]
+               for line in _entry_ops(text) if "tpu_custom_call" in line}
+    assert {"xor_encode", "xor_decode"} <= kernels
